@@ -683,6 +683,9 @@ def emit_json(path: str) -> dict:
 
 if __name__ == "__main__":
     import sys
+
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if "--json" in sys.argv:
         out = sys.argv[sys.argv.index("--json") + 1]
         rec = emit_json(out)

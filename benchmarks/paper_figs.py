@@ -1,9 +1,9 @@
-"""Paper §6 reproductions (Figures 2, 3, 4) on this container's CPU.
+"""Paper §6 reproductions (Figures 2, 3, 4).
 
 Fig 2: validation accuracy vs epochs for train sizes 500..2000.
 Fig 3: per-epoch time and memory vs train size.
-Fig 4: float64 vs float32 accuracy/time/memory (run in a subprocess so
-       jax_enable_x64 never leaks into other benches).
+Fig 4: float64 vs float32 accuracy/time/memory (x64 is enabled only
+       around it, so it never leaks into other benches).
 
 Claims validated (DESIGN.md §1 C1-C5); results land in EXPERIMENTS.md.
 """
@@ -86,7 +86,7 @@ def fig3() -> list[tuple]:
 
 
 def fig4_body() -> list[tuple]:
-    """f64 vs f32 (requires jax_enable_x64; see fig4 subprocess runner)."""
+    """f64 vs f32 (requires jax_enable_x64; see fig4)."""
     rows = []
     for dtype, name in ((jnp.float32, "f32"), (jnp.float64, "f64")):
         ep, mx, tms, mem = [], [], [], 0
@@ -102,23 +102,9 @@ def fig4_body() -> list[tuple]:
 
 
 def fig4() -> list[tuple]:
-    """Run fig4_body in a subprocess with x64 enabled."""
-    import subprocess
-    import sys
-    out = subprocess.run(
-        [sys.executable, "-c",
-         "import jax; jax.config.update('jax_enable_x64', True);"
-         "from benchmarks.paper_figs import fig4_body;"
-         "[print(f'{n},{u:.1f},{d}') for n, u, d in fig4_body()]"],
-        capture_output=True, text=True,
-        env={**__import__('os').environ, "PYTHONPATH": "src"})
-    rows = []
-    for line in out.stdout.strip().splitlines():
-        n, u, d = line.split(",", 2)
-        rows.append((n, float(u), d))
-    if not rows:
-        rows.append(("fig4/error", 0.0, out.stderr.strip()[-120:]))
-    return rows
+    """Run fig4_body in this process with x64 enabled for its duration."""
+    with jax.enable_x64(True):
+        return fig4_body()
 
 
 if __name__ == "__main__":

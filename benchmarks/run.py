@@ -1,7 +1,7 @@
 """Benchmark harness — one section per paper table/figure + the system
 benches. Prints ``name,us_per_call,derived`` CSV to stdout (one row per
 bench; a failing section emits a ``<title>/ERROR`` row and the harness
-keeps going). Invoke from the repo root:
+keeps going, then exits non-zero). Invoke from the repo root:
 
   PYTHONPATH=src:. python benchmarks/run.py        # or: make bench
 
@@ -42,7 +42,7 @@ def _roofline_rows() -> list[tuple]:
     return rows
 
 
-def main() -> None:
+def main() -> int:
     from benchmarks import fl_bench, kernels_bench
     from benchmarks.paper_figs import fig2, fig3, fig4
 
@@ -56,13 +56,20 @@ def main() -> None:
         ("roofline", _roofline_rows),
     ]
     print("name,us_per_call,derived")
+    failed = 0
     for title, fn in sections:
         try:
             for name, us, derived in fn():
                 print(f"{name},{us:.1f},{derived}", flush=True)
         except Exception as e:  # noqa: BLE001 — keep the harness running
             print(f"{title}/ERROR,0.0,{type(e).__name__}:{e}", flush=True)
+            failed += 1
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    import sys
+
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    sys.exit(main())

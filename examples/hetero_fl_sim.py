@@ -26,6 +26,9 @@ from repro.fl import (AsyncBuffered, FleetSpec, FLScenario, LocalTraining,
                       ParticipationPolicy, SyncDrop, UploadPolicy, simulate)
 from repro.models import mlp
 from repro.data import make_gaussian_dataset
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 ROUNDS = 60
 FLEET = ("hub", "high", "high", "mid", "mid", "low", "low", "embedded")

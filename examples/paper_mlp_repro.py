@@ -12,6 +12,9 @@ import jax.numpy as jnp
 from repro.configs.paper_mlp import config
 from repro.data import paper_splits
 from repro.models import mlp
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 EPOCHS = 80
 

@@ -8,7 +8,10 @@ own compressed variant, merged by the mask-aware aggregator — and
 
   PYTHONPATH=src python examples/quickstart.py
 """
+from repro.compile_cache import enable_compile_cache
 from repro.fl import FleetSpec, FLScenario, LocalTraining, simulate
+
+enable_compile_cache()
 
 scenario = FLScenario(
     fleet=FleetSpec(tiers=("hub", "high", "mid", "mid", "low", "embedded"),

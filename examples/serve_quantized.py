@@ -10,6 +10,9 @@ from repro.configs import get_smoke_config
 from repro.core.compression import DEVICE_TIERS, payload_bits
 from repro.core.steps import compress_for_serving, make_serve_step
 from repro.models import get_model
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 GEN = 24
 cfg = get_smoke_config("granite-3-2b")

@@ -1,8 +1,8 @@
 """End-to-end training driver: a ~100M-parameter llama-style model trained
 with the heterogeneous federated step for a few hundred rounds.
 
-Default flags are the real run (~115M params, 300 steps, batch 8 x seq 512)
-— several hours on this CPU container, real-time on one TPU host. Use
+Default flags are the real run (~115M params, 300 steps, batch 8 x seq 512):
+several hours on a CPU; its time on a TPU has not been measured. Use
 --steps/--batch/--seq to scale down for a quick look:
 
   PYTHONPATH=src python examples/train_100m.py --steps 5 --batch 4 --seq 128
@@ -18,6 +18,7 @@ from repro.configs.base import ModelConfig
 from repro.core import TrainState, make_hetero_train_step
 from repro.core.compression import default_tier_plans
 from repro.checkpoint import Checkpointer
+from repro.compile_cache import enable_compile_cache
 from repro.data.synthetic import TokenStream
 from repro.models import get_model
 
@@ -38,6 +39,7 @@ def main() -> None:
     ap.add_argument("--n-tiers", type=int, default=4)
     ap.add_argument("--ckpt-dir", default="")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = config_100m()
     model = get_model(cfg)

@@ -15,7 +15,9 @@ The paper's platform trains *compressed* models; on TPU the hot-spots are:
 
 Each subpackage: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
 public wrapper), ref.py (pure-jnp oracle used by the allclose test sweeps).
-Kernels are validated in interpret mode on CPU; TPU is the target.
+The tests check the kernels in interpret mode on CPU and compile them
+ahead of time for a described TPU v5e (tests/test_tpu_compile.py);
+``chip_smoke.py`` runs them on the chip.
 """
 from repro.kernels.fake_quant.ops import fake_quant  # noqa: F401
 from repro.kernels.masked_matmul.ops import masked_matmul  # noqa: F401

@@ -34,6 +34,11 @@ from repro.kernels.structured_scatter.kernel import (structured_scatter_raw,
 # whole-view gridless call is both exact-sized and machinery-free.
 _BR, _BC = 8, 128
 _BR_MAX, _BC_MAX = 256, 1024
+# A grid step holds 2·T input blocks and one output block, each double
+# buffered, plus the body's num/den accumulators. v5e's default scoped
+# VMEM is 16 MiB; the 4 MiB left over is for the body's per-tier
+# temporaries (a 3-tier (256, 1024) f32 step needs 17.8 MiB in all).
+_VMEM_BUDGET = 12 * 2**20
 
 
 def _rup(n: int, q: int) -> int:
@@ -146,10 +151,26 @@ def structured_scatter_batched(gs, ms, w, w_den=None, *,
     return out.reshape((L,) + tuple(out_shape))
 
 
-def _scatter_tiled(gs, ms, wn, wd, *, rows, cols, out_shape, eps,
-                   interpret):
+def _block_shape(rows: int, cols: int, n_tiers: int) -> tuple:
+    """(br, bc) for a ``rows`` x ``cols`` leaf aggregated over
+    ``n_tiers``: the capped tile-quantized block, rows halved first and
+    then cols, until a grid step's f32 blocks fit ``_VMEM_BUDGET``."""
     br = min(_rup(rows, _BR), _BR_MAX)
     bc = min(_rup(cols, _BC), _BC_MAX)
+    n_blocks = 2 * (2 * n_tiers + 1) + 2
+    while n_blocks * br * bc * 4 > _VMEM_BUDGET:
+        if br > _BR:
+            br = _rup(br // 2, _BR)
+        elif bc > _BC:
+            bc = _rup(bc // 2, _BC)
+        else:
+            break
+    return br, bc
+
+
+def _scatter_tiled(gs, ms, wn, wd, *, rows, cols, out_shape, eps,
+                   interpret):
+    br, bc = _block_shape(rows, cols, len(gs))
     g2s, m2s = [], []
     for g, m in zip(gs, ms):
         r, c = _view2d(tuple(g.shape))

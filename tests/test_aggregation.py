@@ -3,7 +3,7 @@ algorithm the paper poses as the open problem (§3.2)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.aggregation import hetero_aggregate
 from repro.kernels import grad_aggregate

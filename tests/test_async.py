@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 from repro import optim
 from repro.configs.paper_mlp import config
 from repro.core.aggregation import accumulate_cohort, finalize, zeros_like_acc

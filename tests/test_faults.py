@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 from repro import optim
 from repro.configs.paper_mlp import config
 from repro.core.compression import DEVICE_TIERS

@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import (codebook_matmul, fake_quant, grad_aggregate,
                            masked_matmul, structured_scatter)
@@ -278,10 +278,10 @@ def test_structured_scatter_default_wden_and_unsorted_tiers():
 
 
 def test_structured_scatter_gridded_path_matches_whole():
-    """The TPU-shaped tiled wrapper (block quanta, zero-padding, clamped
-    index maps, multi-step grid) must agree bitwise with the gridless
-    whole-leaf call and the oracle — run in interpret mode with blocks
-    forced small enough that the grid really has multiple steps."""
+    """The TPU-shaped tiled wrapper (block quanta, zero-padding) must
+    agree bitwise with the gridless whole-leaf call and the oracle — run
+    in interpret mode; this leaf fits one block (the multi-step grid is
+    covered below)."""
     from repro.kernels.structured_scatter import ops as ss_ops
     out_shape, locals_ = (37, 300), [(37, 300), (19, 140), (7, 65)]
     gs, ms, w, wd = _tiers(out_shape, locals_, seed=5)
@@ -294,6 +294,39 @@ def test_structured_scatter_gridded_path_matches_whole():
     whole = structured_scatter(gs, ms, w, wd, out_shape=out_shape,
                                interpret=True)
     np.testing.assert_array_equal(np.asarray(whole), np.asarray(ref))
+
+
+@pytest.mark.parametrize("n_tiers", [1, 2, 3, 4, 6, 8, 16])
+def test_structured_scatter_block_fits_scoped_vmem(n_tiers):
+    """A grid step's double-buffered 2·T+1 blocks plus the body's two
+    accumulators stay within the VMEM budget, on the (8, 128) tile
+    quanta; small leaves keep their one exact block."""
+    from repro.kernels.structured_scatter import ops as ss_ops
+    for rows, cols in ((4096, 14336), (2048, 2048), (49155, 2048),
+                       (1, 2048)):
+        br, bc = ss_ops._block_shape(rows, cols, n_tiers)
+        assert br % 8 == 0 and bc % 128 == 0
+        assert ((2 * (2 * n_tiers + 1) + 2) * br * bc * 4
+                <= ss_ops._VMEM_BUDGET)
+    assert ss_ops._block_shape(10, 10, n_tiers) == (16, 128)
+    assert ss_ops._block_shape(256, 1024, 2) == (256, 1024)
+
+
+def test_structured_scatter_gridded_multi_step_bitwise():
+    """Three tiers on a leaf wider than one block: the budget halves the
+    block to (128, 1024), so the grid has 3 x 2 steps with clamped index
+    maps and gated partial tiers — still bitwise the oracle."""
+    from repro.kernels.structured_scatter import ops as ss_ops
+    out_shape, locals_ = (264, 1100), [(264, 1100), (132, 550), (66, 275)]
+    assert ss_ops._block_shape(*out_shape, len(locals_)) == (128, 1024)
+    gs, ms, w, wd = _tiers(out_shape, locals_, seed=7)
+    ref = structured_scatter_ref(gs, ms, w, wd, out_shape=out_shape)
+    tiled = ss_ops._scatter_tiled(
+        gs, ms, jnp.asarray(w, jnp.float32).reshape(-1, 1),
+        jnp.asarray(wd, jnp.float32).reshape(-1, 1),
+        rows=264, cols=1100, out_shape=out_shape, eps=1e-8,
+        interpret=True)
+    np.testing.assert_array_equal(np.asarray(tiled), np.asarray(ref))
 
 
 @pytest.mark.parametrize("out_shape,locals_,scalar_masks", [

@@ -3,7 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.numerics import FORMATS, max_finite, quantize_em
 from repro.numerics.float_formats import quantize_int

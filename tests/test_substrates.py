@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro import optim
 from repro.checkpoint import Checkpointer, load_pytree, save_pytree
