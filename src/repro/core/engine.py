@@ -76,11 +76,25 @@ shape (the backend actually used is reported as ``agg_backend``):
   A width=1.0 fleet has identity slices, no real slicing, and takes the
   masked path — bit-identical to it by construction.
 
+Host phases
+-----------
+Each chunk call runs four host phases, each under a
+``jax.profiler.TraceAnnotation`` that a profile (XProf, Perfetto) shows
+on the calling thread beside the device's ops: ``fl.engine.masks``
+(the numpy participation, deadline and fault replay),
+``fl.engine.inputs`` (stacking and transferring the chunk's ``xs``,
+the first call's carry copy), ``fl.engine.dispatch`` (the jitted
+chunk's launch) and ``fl.engine.sync`` (the state hand-back, the
+chunk's one ``device_get`` and the record loop). The spans are always
+on; with no profiler running the four cost a few microseconds a call.
+
 Use it via ``simulate(scenario, rounds, engine="scan", chunk_rounds=N)``
 (``core/scenario.py``) — the async and per-client runtimes fall back to
 the eager loop — or construct it directly around a ``CohortFLServer``.
 ``benchmarks/fl_bench.py`` ``fl/engine_*`` rows measure ≥5× rounds/sec
-over the eager cohort loop at 256 clients / 4 plans / 50 rounds.
+over the eager cohort loop at 256 clients / 4 plans / 50 rounds on
+XLA:CPU. On a TPU v5e the host phases above take most of a 100 000-client
+chunk call (PERF.md §5).
 """
 from __future__ import annotations
 
@@ -379,7 +393,7 @@ class ScanEngine:
                 # grid branch's per-edge adds in edge order; empty edges
                 # add exact zeros (bitwise identity). Wall/bytes/counts
                 # are computed HOST-side from the flat masks (float64,
-                # exactly the eager expressions) in _run_chunk.
+                # exactly the eager expressions) in _sync.
                 per_cohort.append((g_sum, masks,
                                    srv.cohorts[ci].plan.weight,
                                    x["count"][ci], None))
@@ -422,6 +436,8 @@ class ScanEngine:
 
     # -------------------------------------------------------------- host
 
+    @functools.partial(jax.profiler.annotate_function,
+                       name="fl.engine.masks")
     def _host_masks(self, R: int, participation=None):
         """The chunk's stacked participation: replay the eager path's
         per-round ``default_rng([seed, step])`` sampling, float64
@@ -476,10 +492,21 @@ class ScanEngine:
         return parts, wparts, dropped, dropouts, corrs
 
     def _run_chunk(self, R: int, participation=None) -> list[dict]:
+        step0 = self.server.step
+        masks = self._host_masks(R, participation)
+        with jax.profiler.TraceAnnotation("fl.engine.inputs"):
+            carry, xs, datas = self._stage_inputs(step0, R, masks)
+        with jax.profiler.TraceAnnotation("fl.engine.dispatch"):
+            out, metrics = self._chunk(carry, xs, datas)
+        with jax.profiler.TraceAnnotation("fl.engine.sync"):
+            return self._sync(step0, R, out, metrics, masks)
+
+    def _stage_inputs(self, step0: int, R: int, masks):
+        """The chunk's arguments from its ``_host_masks``: the stacked
+        per-round inputs ``xs`` on the device, the (donatable) carry and
+        the cohorts' data."""
         srv = self.server
-        step0 = srv.step
-        parts, wparts, dropped, dropouts, corrs = self._host_masks(
-            R, participation)
+        parts, wparts, _, _, corrs = masks
         xs = {
             "step": jnp.asarray(np.arange(step0, step0 + R), jnp.int32),
             "has": jnp.asarray([any(p.any() for p in parts[r])
@@ -541,8 +568,15 @@ class ScanEngine:
             # copy once, then chunks donate engine-produced state freely
             carry = jax.tree.map(jnp.array, carry)
         datas = tuple(c.data for c in srv.cohorts)
-        (params, opt_state, efs), metrics = self._chunk(carry, xs, datas)
-        self._last_out = (params, opt_state, efs)
+        return carry, xs, datas
+
+    def _sync(self, step0: int, R: int, out, metrics, masks) -> list[dict]:
+        """Hand the chunk's state back to the server, sync its metrics
+        to host once, and append the eager-schema records."""
+        srv = self.server
+        parts, wparts, dropped, dropouts, corrs = masks
+        params, opt_state, efs = out
+        self._last_out = out
         srv.params, srv.opt_state = params, opt_state
         srv.step = step0 + R
         if srv.upload_quant is not None and srv.error_feedback:
