@@ -82,8 +82,9 @@ Each chunk call runs four host phases, each under a
 ``jax.profiler.TraceAnnotation`` that a profile (XProf, Perfetto) shows
 on the calling thread beside the device's ops: ``fl.engine.masks``
 (the numpy participation, deadline and fault replay),
-``fl.engine.inputs`` (stacking and transferring the chunk's ``xs``,
-the first call's carry copy), ``fl.engine.dispatch`` (the jitted
+``fl.engine.inputs`` (building the chunk's ``xs`` on the host in its
+final dtypes and moving it in one transfer, the first call's carry
+copy), ``fl.engine.dispatch`` (the jitted
 chunk's launch) and ``fl.engine.sync`` (the state hand-back, the
 chunk's one ``device_get`` and the record loop). The spans are always
 on; with no profiler running the four cost a few microseconds a call.
@@ -206,6 +207,11 @@ class ScanEngine:
         self._n_batch = [next(iter(c.data.values()))
                          .shape[2 if self._topology else 1]
                          for c in srv.cohorts]
+        # each cohort's static columns of the chunk's packed per-client
+        # inputs: its clients, or its grid's rows under a topology
+        ends = np.cumsum([c.cap if self._topology else c.size
+                          for c in srv.cohorts]).tolist()
+        self._cols = [slice(a, b) for a, b in zip([0] + ends[:-1], ends)]
         # structured (width-sliced) cohorts, DESIGN.md §13: per-cohort
         # slice specs (None = masked plan) drive the in-body scatter, and
         # EF carries are allocated at each cohort's LOCAL model shapes
@@ -431,6 +437,15 @@ class ScanEngine:
         return (params, opt_state, tuple(new_efs)), metrics
 
     def _chunk_fn(self, carry, xs, datas):
+        # the per-client inputs arrive packed across cohorts, one host
+        # transfer each (_stage_inputs); the rounds read them per cohort
+        xs = dict(xs)
+        for k in ("part", "wpart", "corrupt", "uid"):
+            if k in xs:
+                xs[k] = tuple(xs[k][..., col] for col in self._cols)
+        if "count" in xs:
+            xs["count"] = tuple(xs["count"][..., ci]
+                                for ci in range(len(self._cols)))
         return jax.lax.scan(
             functools.partial(self._round_body, datas=datas), carry, xs)
 
@@ -504,63 +519,57 @@ class ScanEngine:
     def _stage_inputs(self, step0: int, R: int, masks):
         """The chunk's arguments from its ``_host_masks``: the stacked
         per-round inputs ``xs`` on the device, the (donatable) carry and
-        the cohorts' data."""
+        the cohorts' data. ``xs`` is built on the host in the dtypes the
+        program takes (bool masks cast to float32 exactly), each
+        per-client input packed across cohorts into one array whose last
+        axis ``_chunk_fn`` slices by ``self._cols``, and moved in one
+        ``device_put``: every leaf is a host-to-device round trip of its
+        own, so the leaf count sets this phase's time (PERF.md §5)."""
         srv = self.server
         parts, wparts, _, _, corrs = masks
+
+        def packed(rows):
+            return np.stack([np.concatenate(rows[r], axis=-1)
+                             for r in range(R)]).astype(np.float32)
+
         xs = {
-            "step": jnp.asarray(np.arange(step0, step0 + R), jnp.int32),
-            "has": jnp.asarray([any(p.any() for p in parts[r])
-                                for r in range(R)]),
+            "step": np.arange(step0, step0 + R, dtype=np.int32),
+            "has": np.array([any(p.any() for p in parts[r])
+                             for r in range(R)], bool),
         }
         if srv.faults is not None and not self._topology:
-            xs["wpart"] = tuple(
-                jnp.asarray(np.stack([wparts[r][ci] for r in range(R)]),
-                            jnp.float32)
-                for ci in range(len(srv.cohorts)))
+            xs["wpart"] = packed(wparts)
         if self._fault_uploads:
-            offs = np.cumsum([0] + [c.size for c in srv.cohorts])
-            n_total = srv.n_clients
-            xs["corrupt"] = tuple(
-                jnp.asarray(np.stack([corrs[r][ci] for r in range(R)]),
-                            jnp.float32)
-                for ci in range(len(srv.cohorts)))
+            xs["corrupt"] = packed(corrs)
             # per-upload uid = step * n_clients + flat client index — the
             # eager fault dispatch's exact key, so the element-subset
             # corruption PRNG draws identically in both paths
-            xs["uid"] = tuple(
-                jnp.asarray(np.stack(
-                    [(step0 + r) * n_total + np.arange(offs[ci],
-                                                       offs[ci + 1])
-                     for r in range(R)]), jnp.int32)
-                for ci in range(len(srv.cohorts)))
+            n_total = srv.n_clients
+            xs["uid"] = ((step0 + np.arange(R))[:, None] * n_total
+                         + np.arange(n_total)).astype(np.int32)
+        placement = None
         if self._topology:
             # grid xs (DESIGN.md §16): the flat sampled masks scattered
-            # into each cohort's (E, cap) grid plus per-edge participant
-            # counts (exact small ints). Under a mesh the stacked grids
-            # are placed shard-aligned with the cohort data: rounds
+            # into each cohort's (E, cap) float32 grid, the grids side by
+            # side as (E, sum cap), plus per-edge participant counts
+            # (exact small ints) as (E, cohorts). Under a mesh both are
+            # placed shard-aligned with the cohort data: rounds
             # replicated, edges split on the "data" axis.
-            xs["part"] = tuple(
-                jnp.asarray(np.stack([scatter_part(c, parts[r][ci])
-                                      for r in range(R)]))
-                for ci, c in enumerate(srv.cohorts))
-            xs["count"] = tuple(
-                jnp.asarray(np.stack(
-                    [np.bincount(c.edge_index[parts[r][ci]],
-                                 minlength=c.n_edges)
-                     for r in range(R)]), jnp.float32)
-                for ci, c in enumerate(srv.cohorts))
+            xs["part"] = packed([[scatter_part(c, parts[r][ci])
+                                  for ci, c in enumerate(srv.cohorts)]
+                                 for r in range(R)])
+            xs["count"] = packed([[np.bincount(c.edge_index[parts[r][ci]],
+                                               minlength=c.n_edges)[:, None]
+                                   for ci, c in enumerate(srv.cohorts)]
+                                  for r in range(R)])
             if srv.mesh is not None:
                 sh = jax.sharding.NamedSharding(
                     srv.mesh, jax.sharding.PartitionSpec(None, "data"))
-                xs["part"] = tuple(jax.device_put(p, sh)
-                                   for p in xs["part"])
-                xs["count"] = tuple(jax.device_put(c, sh)
-                                    for c in xs["count"])
+                placement = {k: (sh if k in ("part", "count") else None)
+                             for k in xs}
         else:
-            xs["part"] = tuple(
-                jnp.asarray(np.stack([parts[r][ci] for r in range(R)]),
-                            jnp.float32)
-                for ci in range(len(srv.cohorts)))
+            xs["part"] = packed(parts)
+        xs = jax.device_put(xs, placement)
         carry = (srv.params, srv.opt_state, self._ef_carry())
         if not self._owns(carry):
             # the carry is donated: never eat buffers the caller may still

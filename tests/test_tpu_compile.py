@@ -102,12 +102,7 @@ def test_scan_chunk_compiles_with_structured_kernel(one_chip):
     assert eng.agg_backend == "pallas_structured"
     # the chunk's arguments, as ScanEngine._run_chunk builds them for a
     # clean flat fleet
-    carry = (srv.params, srv.opt_state, eng._ef_carry())
-    xs = {"step": jnp.zeros((rounds,), jnp.int32),
-          "has": jnp.ones((rounds,), bool),
-          "part": tuple(jnp.ones((rounds, c.size), jnp.float32)
-                        for c in srv.cohorts)}
-    datas = tuple(c.data for c in srv.cohorts)
+    carry, xs, datas = eng._stage_inputs(0, rounds, eng._host_masks(rounds))
     args = jax.tree.map(lambda x: _spec(x.shape, one_chip, x.dtype),
                         (carry, xs, datas))
     compiled = eng._chunk.lower(*args).compile()
