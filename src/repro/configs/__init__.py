@@ -9,6 +9,7 @@ from repro.configs.base import ModelConfig, ShapeConfig, SHAPES, smoke_reduce
 _ARCH_MODULES = {
     "granite-moe-1b-a400m": "granite_moe_1b",
     "granite-3-2b": "granite_3_2b",
+    "granite-4.0-h-micro": "granite_4_h_micro",
     "xlstm-1.3b": "xlstm_1_3b",
     "zamba2-2.7b": "zamba2_2_7b",
     "llama3.2-3b": "llama3_2_3b",

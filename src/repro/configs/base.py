@@ -17,7 +17,7 @@ class ModelConfig:
     """Architecture hyper-parameters (transformer backbone only for vlm/audio)."""
 
     name: str
-    family: str  # dense | moe | ssm | hybrid | vlm | audio
+    family: str  # dense | moe | ssm | hybrid | granite_hybrid | vlm | audio
     num_layers: int
     d_model: int
     num_heads: int
@@ -52,6 +52,10 @@ class ModelConfig:
     # mamba layers; 0 = n/a
     attn_every: int = 0
 
+    # granite_hybrid: the mixer of each layer, "mamba" or "attention", in
+    # order; every layer has its own parameters and its own SwiGLU MLP
+    layer_types: tuple = ()
+
     # encoder-decoder (whisper): encoder layer count + fixed encoder length
     encoder_layers: int = 0
     encoder_seq: int = 1500
@@ -61,6 +65,16 @@ class ModelConfig:
 
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
+
+    # Granite's multipliers. The defaults are the identity: the embedding
+    # and the residual branches unscaled, attention scaled by
+    # 1/sqrt(head_dim) (attention_multiplier 0), logits undivided.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
+    # False: no position embedding at all (NoPE)
+    use_rope: bool = True
     dtype: str = "bfloat16"  # compute dtype (params kept f32)
     # use the Pallas flash-attention kernel instead of the jnp chunked
     # path (TPU deployments; interpret-mode on CPU is correct but slow)

@@ -45,6 +45,13 @@ import jax
 import jax.numpy as jnp
 
 _EXCLUDE = ("router",)
+# Mamba2's per-channel and per-head leaves: the depthwise conv's 4-tap
+# filters and biases, A, dt's bias, D and the gated norm's scale. Each is
+# a vector per layer (the filter a (channels, 4) stack of them); stacked
+# over layers they are 2-D, but they stay full precision and full shape
+# as vectors do. A pruned 4-tap filter loses most of its taps per channel.
+_MAMBA_VECTORS = ("conv_x_w", "conv_x_b", "conv_bc_w", "conv_bc_b", "a_log",
+                  "dt_bias", "d_skip", "gate_norm")
 
 
 def _path_str(path) -> str:
@@ -54,10 +61,14 @@ def _path_str(path) -> str:
 def compressible(path, leaf) -> bool:
     """The compression policy shared by every path in ``compression/``:
     matrix-shaped (ndim >= 2) leaves compress; 1-D leaves (norm scales,
-    gates, biases — quantization-sensitive) and the MoE router
-    (load-balance stability) stay full precision / full shape."""
+    gates, biases — quantization-sensitive), the MoE router (load-balance
+    stability) and Mamba2's per-channel and per-head leaves
+    (``_MAMBA_VECTORS``) stay full precision / full shape."""
     p = _path_str(path)
     if any(x in p for x in _EXCLUDE):
+        return False
+    parts = p.split("/")
+    if "mamba" in parts[:-1] and parts[-1] in _MAMBA_VECTORS:
         return False
     return getattr(leaf, "ndim", len(getattr(leaf, "shape", ()))) >= 2
 

@@ -50,7 +50,15 @@ def stack_shards(shards: list[dict]) -> dict:
     truncated to the common floor (with Dirichlet skew this drops tail
     samples; use equal-size IID shards when exact data parity with the
     per-client loop matters). Single host sync-free reshape, done once at
-    cohort build time, not per round.
+    cohort build time, not per round. Host (numpy) shards are stacked on
+    the host and moved to the device in one transfer, not one a shard.
     """
     n = min(next(iter(s.values())).shape[0] for s in shards)
-    return {k: jnp.stack([s[k][:n] for s in shards]) for k in shards[0]}
+    out = {}
+    for k in shards[0]:
+        parts = [s[k][:n] for s in shards]
+        if all(isinstance(x, np.ndarray) for x in parts):
+            out[k] = jnp.asarray(np.stack(parts))
+        else:
+            out[k] = jnp.stack(parts)
+    return out

@@ -67,20 +67,23 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "n_rep", "causal", "window", "q_offset", "block_q", "block_k",
+    "n_rep", "causal", "window", "q_offset", "scale", "block_q", "block_k",
     "s_valid", "interpret"))
 def flash_attention_raw(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         n_rep: int = 1, causal: bool = True, window: int = 0,
-                        q_offset: int = 0, block_q: int = 128,
+                        q_offset: int = 0, scale: float | None = None,
+                        block_q: int = 128,
                         block_k: int = 128, s_valid: int | None = None,
                         interpret: bool = False) -> jax.Array:
     """q: (BHq, Tq, hd); k, v: (BHkv, S, hd) with BHq == BHkv * n_rep.
-    Tq % block_q == 0, S % block_k == 0 (pad before; mask via s_valid)."""
+    Tq % block_q == 0, S % block_k == 0 (pad before; mask via s_valid).
+    ``scale`` multiplies the scores (None: 1/sqrt(hd))."""
     bh, tq, hd = q.shape
     s = k.shape[1]
     bq, bk = min(block_q, tq), min(block_k, s)
     k_steps = s // bk
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
     if s_valid is None:
         s_valid = s
     kern = functools.partial(

@@ -21,20 +21,23 @@ def _auto_interpret() -> bool:
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    q_offset: int = 0, interpret: bool | None = None):
-    """q: (B, Tq, H, hd); k, v: (B, S, Hkv, hd) -> (B, Tq, H, hd)."""
-    return _flash(q, k, v, causal, window, q_offset, interpret)
+                    q_offset: int = 0, scale: float | None = None,
+                    interpret: bool | None = None):
+    """q: (B, Tq, H, hd); k, v: (B, S, Hkv, hd) -> (B, Tq, H, hd).
+    ``scale`` multiplies the scores (None: 1/sqrt(hd))."""
+    return _flash(q, k, v, causal, window, q_offset, scale, interpret)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, window, q_offset, interpret):
-    return _forward(q, k, v, causal, window, q_offset, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, window, q_offset, scale, interpret):
+    return _forward(q, k, v, causal, window, q_offset, scale, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "q_offset",
-                                             "interpret"))
+                                             "scale", "interpret"))
 def _forward(q, k, v, causal: bool = True, window: int = 0,
-             q_offset: int = 0, interpret: bool | None = None):
+             q_offset: int = 0, scale: float | None = None,
+             interpret: bool | None = None):
     if interpret is None:
         interpret = _auto_interpret()
     b, tq, h, hd = q.shape
@@ -59,21 +62,23 @@ def _forward(q, k, v, causal: bool = True, window: int = 0,
         v2 = jnp.pad(v2, ((0, 0), (0, pad_k), (0, 0)))
 
     o = flash_attention_raw(q2, k2, v2, n_rep=n_rep, causal=causal,
-                            window=window, q_offset=q_offset,
+                            window=window, q_offset=q_offset, scale=scale,
                             s_valid=s, interpret=interpret)
     if pad_q:
         o = o[:, :tq, :]
     return jnp.moveaxis(o.reshape(b, h, tq, hd), 1, 2)
 
 
-def _fwd(q, k, v, causal, window, q_offset, interpret):
-    return _forward(q, k, v, causal, window, q_offset, interpret), (q, k, v)
+def _fwd(q, k, v, causal, window, q_offset, scale, interpret):
+    return (_forward(q, k, v, causal, window, q_offset, scale, interpret),
+            (q, k, v))
 
 
-def _bwd(causal, window, q_offset, interpret, res, g):
+def _bwd(causal, window, q_offset, scale, interpret, res, g):
     q, k, v = res
     _, vjp = jax.vjp(lambda q, k, v: flash_attention_ref(
-        q, k, v, causal=causal, window=window, q_offset=q_offset), q, k, v)
+        q, k, v, causal=causal, window=window, q_offset=q_offset,
+        scale=scale), q, k, v)
     return vjp(g)
 
 

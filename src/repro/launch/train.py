@@ -15,6 +15,7 @@ import json
 import time
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import optim
 from repro.configs import get_config, get_smoke_config
@@ -23,9 +24,37 @@ from repro.core import TrainState, make_hetero_train_step
 from repro.core.compression import default_tier_plans
 from repro.checkpoint import Checkpointer
 from repro.data.synthetic import make_train_batch
-from repro.launch.mesh import make_host_mesh, num_batch_shards
+from repro.launch.mesh import batch_axes, make_host_mesh, num_batch_shards
 from repro.models import get_model
-from repro.models.sharding import named, param_spec_tree, set_rules
+from repro.models.sharding import (activation_rules, make_activation_rules,
+                                   named, param_spec_tree)
+
+
+def sharded_train_step(model, optimizer, plans, mesh, state):
+    """The tier-scanned step jitted under ``mesh`` with the state donated.
+
+    State shardings come from ``param_spec_tree`` (heads, d_ff, vocab on
+    "model"), the mask-aware accumulators are constrained to the params'
+    shardings, and the model's activation hints follow
+    ``make_activation_rules`` while the step traces. ``state`` may hold
+    ``jax.ShapeDtypeStruct``s. Returns (jitted step, state shardings,
+    batch sharding: tiers whole, each tier's batch over the data axes)."""
+    baxes = batch_axes(mesh)
+    state_sh = named(mesh, param_spec_tree(state, mesh.shape["model"]))
+    rules = make_activation_rules(
+        mesh, baxes, vocab_ok=model.cfg.vocab_size % mesh.shape["model"] == 0)
+    step = make_hetero_train_step(model, optimizer, plans,
+                                  num_groups=num_batch_shards(mesh),
+                                  acc_shardings=state_sh["params"])
+
+    def traced(state, batch):
+        with activation_rules(rules):
+            return step(state, batch)
+
+    batch_sh = NamedSharding(mesh, P(None, baxes))
+    jitted = jax.jit(traced, in_shardings=(state_sh, batch_sh),
+                     out_shardings=(state_sh, None), donate_argnums=(0,))
+    return jitted, state_sh, batch_sh
 
 
 def main() -> None:
@@ -49,25 +78,18 @@ def main() -> None:
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
     shape = ShapeConfig("cli", args.seq, args.batch, "train")
     mesh = make_host_mesh(args.model_parallel)
-    ng = num_batch_shards(mesh)
-    set_rules({})
 
     model = get_model(cfg)
     opt = optim.adamw(optim.warmup_cosine(args.lr, args.warmup, args.steps))
-    step_fn = make_hetero_train_step(model, opt,
-                                     default_tier_plans(args.n_tiers),
-                                     num_groups=ng)
-
     state = TrainState.create(model, opt, jax.random.PRNGKey(args.seed))
     n_params = sum(x.size for x in jax.tree.leaves(state["params"]))
     print(f"arch={cfg.name} params={n_params:,} mesh={dict(mesh.shape)} "
           f"tiers={args.n_tiers}")
 
-    state_sh = named(mesh, param_spec_tree(state, mesh.shape["model"]))
+    jstep, state_sh, batch_sh = sharded_train_step(
+        model, opt, default_tier_plans(args.n_tiers), mesh, state)
     with mesh:
         state = jax.device_put(state, state_sh)
-        jstep = jax.jit(step_fn, in_shardings=(state_sh, None),
-                        out_shardings=(state_sh, None))
 
         ckpt = Checkpointer(args.ckpt_dir) if args.ckpt_dir else None
         start = 0
@@ -79,7 +101,7 @@ def main() -> None:
         for i in range(start, args.steps):
             batch = make_train_batch(cfg, shape, n_tiers=args.n_tiers,
                                      seed=args.seed, index=i)
-            state, metrics = jstep(state, batch)
+            state, metrics = jstep(state, jax.device_put(batch, batch_sh))
             if (i + 1) % args.log_every == 0 or i == start:
                 loss = float(metrics["loss"])
                 dt = (time.time() - t0) / (i - start + 1)

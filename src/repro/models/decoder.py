@@ -5,6 +5,12 @@ embeddings prepended to text tokens).
 Layers are scanned (stacked params, `lax.scan`) with optional remat so the
 HLO stays one-layer-sized regardless of depth; decode runs the same scan
 over per-layer ring-buffer KV caches.
+
+Granite's multipliers (``cfg.embedding_multiplier``, ``residual_multiplier``
+on both branches of a layer, ``attention_multiplier`` as the attention
+scale, ``logits_scaling`` dividing the logits) and NoPE (``use_rope``
+False) apply where the config sets them; at their identity defaults no
+op is added.
 """
 from __future__ import annotations
 
@@ -55,21 +61,23 @@ def _ffn(lp, x, cfg, num_groups):
         y, aux = moe_apply(lp["moe"], h, cfg, num_groups)
     else:
         y, aux = L.swiglu(lp["mlp"], h), jnp.float32(0.0)
-    return x + y, aux
+    return x + L.scaled(y, cfg.residual_multiplier), aux
 
 
 def _block(lp, x, cfg, window, num_groups):
     h = L.attn_forward(lp["attn"], L.rms_norm(x, lp["ln1"], cfg.norm_eps),
-                       cfg, window=window)
-    x = hint(x + h, "act_btd")
+                       cfg, window=window, use_rope=cfg.use_rope,
+                       scale=L.attn_scale(cfg))
+    x = hint(x + L.scaled(h, cfg.residual_multiplier), "act_btd")
     x, aux = _ffn(lp, x, cfg, num_groups)
     return hint(x, "act_btd"), aux
 
 
 def _block_decode(lp, x, cache_l, pos, cfg, window, num_groups):
     h, cache_l = L.attn_decode(lp["attn"], L.rms_norm(x, lp["ln1"], cfg.norm_eps),
-                               cache_l, pos, cfg, window=window)
-    x = x + h
+                               cache_l, pos, cfg, window=window,
+                               use_rope=cfg.use_rope, scale=L.attn_scale(cfg))
+    x = x + L.scaled(h, cfg.residual_multiplier)
     x, _ = _ffn(lp, x, cfg, num_groups)
     return x, cache_l
 
@@ -77,7 +85,8 @@ def _block_decode(lp, x, cache_l, pos, cfg, window, num_groups):
 # ---------------------------------------------------------------- forward
 
 def _embed_inputs(params, tokens, cfg, patches):
-    x = L.embed(params["embed"], tokens, jnp.dtype(cfg.dtype))
+    x = L.scaled(L.embed(params["embed"], tokens, jnp.dtype(cfg.dtype)),
+                 cfg.embedding_multiplier)
     if patches is not None:
         pe = L.dense(params["projector"], patches.astype(x.dtype))
         x = jnp.concatenate([pe, x], axis=1)
@@ -90,6 +99,8 @@ def _unembed(params, x, cfg):
         logits = L.unembed(x, params["embed"])
     else:
         logits = L.dense(params["lm_head"], x.astype(jnp.float32))
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     return hint(logits, "logits")
 
 
@@ -135,11 +146,14 @@ def prefill(params, tokens, cfg, *, patches=None, window: int = 0,
         q = L.dense(lp["attn"]["wq"], h_in)
         k = L.dense(lp["attn"]["wk"], h_in)
         v = L.dense(lp["attn"]["wv"], h_in)
-        pos = jnp.arange(t)
-        q = L.rope(q, pos, cfg.rope_theta)
-        k = L.rope(k, pos, cfg.rope_theta)
-        o = L.chunked_attention(q, k, v, causal=True, window=window)
-        x = hint(x + L.dense(lp["attn"]["wo"], o.reshape(x.shape[0], t, -1)), "act_btd")
+        if cfg.use_rope:
+            pos = jnp.arange(t)
+            q = L.rope(q, pos, cfg.rope_theta)
+            k = L.rope(k, pos, cfg.rope_theta)
+        o = L.chunked_attention(q, k, v, causal=True, window=window,
+                                scale=L.attn_scale(cfg))
+        h = L.dense(lp["attn"]["wo"], o.reshape(x.shape[0], t, -1))
+        x = hint(x + L.scaled(h, cfg.residual_multiplier), "act_btd")
         x, _ = _ffn(lp, x, cfg, num_groups)
         return hint(x, "act_btd"), {"k": k, "v": v}
 
@@ -165,7 +179,8 @@ def decode_step(params, cache, tokens, pos, cfg, *, window: int = 0,
                 num_groups: int = 1):
     """One decode step. tokens: (B, 1); pos: scalar int32 (shared across batch
     in this synthetic setting). Returns (logits (B, 1, V), cache)."""
-    x = L.embed(params["embed"], tokens, jnp.dtype(cfg.dtype))
+    x = L.scaled(L.embed(params["embed"], tokens, jnp.dtype(cfg.dtype)),
+                 cfg.embedding_multiplier)
 
     def body(x, xs):
         lp, cl = xs

@@ -45,6 +45,17 @@ def dense(p: Params, x: Array) -> Array:
     return y
 
 
+def scaled(x: Array, multiplier: float) -> Array:
+    """x times a configured multiplier; x itself (no op) where it is 1."""
+    return x if multiplier == 1.0 else x * multiplier
+
+
+def attn_scale(cfg) -> float | None:
+    """The configured attention scale; None (1/sqrt(head_dim)) unless the
+    config sets ``attention_multiplier``."""
+    return cfg.attention_multiplier or None
+
+
 # ---------------------------------------------------------------------- norms
 
 def rms_norm(x: Array, w: Array, eps: float = 1e-5) -> Array:
@@ -92,20 +103,22 @@ def repeat_kv(k: Array, n_rep: int) -> Array:
 
 def chunked_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
                       window: int = 0, q_chunk: int = 1024,
-                      q_offset: int = 0) -> Array:
+                      q_offset: int = 0, scale: float | None = None) -> Array:
     """Memory-bounded attention: scan over query chunks (scores never exceed
     (B, H, q_chunk, S)). O(T*S) FLOPs, O(q_chunk*S) memory.
 
     q: (B, T, H, hd); k, v: (B, S, Hkv, hd). Returns (B, T, H, hd).
     window > 0 masks keys further than `window` behind the query (sliding
-    window); q_offset is the absolute position of q[0] relative to k[0].
+    window); q_offset is the absolute position of q[0] relative to k[0];
+    scale multiplies the scores (None: 1/sqrt(hd)).
     """
     b, t, h, hd = q.shape
     s = k.shape[1]
     n_rep = h // k.shape[2]
     k = repeat_kv(k, n_rep)
     v = repeat_kv(v, n_rep)
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
     q_chunk = min(q_chunk, t)
     if t % q_chunk:
         q_chunk = t  # fall back: unchunked (small T)
@@ -167,20 +180,23 @@ def kv_cache_update(cache: Params, k_new: Array, v_new: Array, pos: Array) -> Pa
     }
 
 
-def decode_attention(q: Array, cache: Params, *, window: int = 0) -> Array:
+def decode_attention(q: Array, cache: Params, *, window: int = 0,
+                     scale: float | None = None) -> Array:
     """Single-token attention against the ring cache.
 
     q: (B, 1, H, hd). Masking is via slot_pos: valid slots satisfy
     0 <= slot_pos (written) and, with a window, slot_pos > pos - window —
     but since the ring overwrites slots older than W=window, every written
-    slot is in-window by construction; we still mask empties.
+    slot is in-window by construction; we still mask empties. scale
+    multiplies the scores (None: 1/sqrt(hd)).
     """
     from repro.models.sharding import hint
     h = q.shape[2]
     n_rep = h // cache["k"].shape[2]
     k = repeat_kv(cache["k"], n_rep)
     v = repeat_kv(cache["v"], n_rep)
-    scale = 1.0 / math.sqrt(q.shape[-1])
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     scores = hint(scores, "dec_scores")          # (B, H, 1, S): S on "model"
     valid = cache["slot_pos"] >= 0
@@ -205,8 +221,10 @@ def init_attn(key, cfg) -> Params:
 
 def attn_forward(p: Params, x: Array, cfg, *, window: int = 0,
                  positions: Array | None = None, use_rope: bool = True,
-                 kv_src: Array | None = None, causal: bool = True) -> Array:
-    """Full-sequence attention (train / prefill). kv_src != None => cross-attn."""
+                 kv_src: Array | None = None, causal: bool = True,
+                 scale: float | None = None) -> Array:
+    """Full-sequence attention (train / prefill). kv_src != None => cross-attn.
+    scale multiplies the scores (None: 1/sqrt(head_dim))."""
     b, t, _ = x.shape
     src = x if kv_src is None else kv_src
     q = dense(p["wq"], x)                      # (B, T, H, hd)
@@ -220,15 +238,16 @@ def attn_forward(p: Params, x: Array, cfg, *, window: int = 0,
     if getattr(cfg, "use_flash", False):
         from repro.kernels.flash_attention import flash_attention
         o = flash_attention(q, k, v, causal=causal and kv_src is None,
-                            window=window)
+                            window=window, scale=scale)
     else:
         o = chunked_attention(q, k, v, causal=causal and kv_src is None,
-                              window=window)
+                              window=window, scale=scale)
     return dense(p["wo"], o.reshape(b, t, -1))
 
 
 def attn_decode(p: Params, x: Array, cache: Params, pos: Array, cfg, *,
-                window: int = 0, use_rope: bool = True) -> tuple[Array, Params]:
+                window: int = 0, use_rope: bool = True,
+                scale: float | None = None) -> tuple[Array, Params]:
     """Single-step decode. x: (B, 1, D); pos: scalar int32."""
     b = x.shape[0]
     q = dense(p["wq"], x)
@@ -239,7 +258,7 @@ def attn_decode(p: Params, x: Array, cache: Params, pos: Array, cfg, *,
         q = rope(q, ppos, cfg.rope_theta)
         k = rope(k, ppos, cfg.rope_theta)
     cache = kv_cache_update(cache, k, v, pos)
-    o = decode_attention(q, cache, window=window)
+    o = decode_attention(q, cache, window=window, scale=scale)
     return dense(p["wo"], o.reshape(b, 1, -1)), cache
 
 

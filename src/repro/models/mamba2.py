@@ -4,6 +4,9 @@ TPU/MXU-friendly) + O(1) recurrent decode step.
 Simplifications vs. the reference CUDA implementation (documented in
 DESIGN.md): n_groups = 1 (B/C shared across heads), no sequence-parallel
 conv halo (conv runs full-sequence under pjit; XLA shards the batch dim).
+Every head-indexed leaf is laid out by head (``init_mamba``), so a
+"model"-sharded layer computes its own heads' z, x, dt and conv with no
+resharding and B/C replicated.
 """
 from __future__ import annotations
 
@@ -26,41 +29,60 @@ def dims(cfg):
 
 
 def init_mamba(key, cfg) -> dict:
-    d_in, nheads, conv_dim = dims(cfg)
-    ks = jax.random.split(key, 5)
-    proj_out = 2 * d_in + 2 * cfg.ssm_state + nheads   # z, x, B, C, dt
+    """The input projection is one leaf per part, each laid out by head:
+    ``in_z``/``in_x`` (D, d_in) and ``in_dt`` (D, H) hold head h's columns
+    at [h*P, (h+1)*P) and h, so sharding their last axis gives each rank
+    whole heads; ``in_bc`` (D, 2N) is B and C, which every head shares
+    (n_groups = 1). The depthwise conv over [x | B | C] is split the same
+    way: ``conv_x_*`` per x channel, ``conv_bc_*`` for B and C."""
+    d_in, nheads, _ = dims(cfg)
+    n, w = cfg.ssm_state, cfg.conv_width
+    ks = jax.random.split(key, 7)
     return {
-        "in_proj": init_dense(ks[0], cfg.d_model, proj_out),
-        "conv_w": jax.random.normal(ks[1], (conv_dim, cfg.conv_width), jnp.float32)
-                  * (1.0 / math.sqrt(cfg.conv_width)),
-        "conv_b": jnp.zeros((conv_dim,), jnp.float32),
+        "in_z": init_dense(ks[0], cfg.d_model, d_in),
+        "in_x": init_dense(ks[1], cfg.d_model, d_in),
+        "in_bc": init_dense(ks[2], cfg.d_model, 2 * n),
+        "in_dt": init_dense(ks[3], cfg.d_model, nheads),
+        "conv_x_w": jax.random.normal(ks[4], (d_in, w), jnp.float32)
+                    * (1.0 / math.sqrt(w)),
+        "conv_x_b": jnp.zeros((d_in,), jnp.float32),
+        "conv_bc_w": jax.random.normal(ks[5], (2 * n, w), jnp.float32)
+                     * (1.0 / math.sqrt(w)),
+        "conv_bc_b": jnp.zeros((2 * n,), jnp.float32),
         "a_log": jnp.log(jnp.linspace(1.0, 16.0, nheads).astype(jnp.float32)),
         "dt_bias": jnp.log(jnp.expm1(jnp.linspace(1e-3, 0.1, nheads)).astype(jnp.float32)),
         "d_skip": jnp.ones((nheads,), jnp.float32),
         "gate_norm": jnp.ones((d_in,), jnp.float32),
-        "out_proj": init_dense(ks[4], d_in, cfg.d_model,
+        "out_proj": init_dense(ks[6], d_in, cfg.d_model,
                                scale=1.0 / math.sqrt(d_in * 2 * cfg.num_layers)),
     }
 
 
-def _split_proj(p, u, cfg):
-    d_in, nheads, _ = dims(cfg)
-    n = cfg.ssm_state
-    zxbcdt = dense(p["in_proj"], u)
-    z, x, bmat, cmat, dt = jnp.split(
-        zxbcdt, [d_in, 2 * d_in, 2 * d_in + n, 2 * d_in + 2 * n], axis=-1)
-    return z, x, bmat, cmat, dt
+def _project(p, u):
+    """(z, x, bc, dt) of the input projection."""
+    return (dense(p["in_z"], u), dense(p["in_x"], u), dense(p["in_bc"], u),
+            dense(p["in_dt"], u))
 
 
-def _causal_conv(p, xbc, cfg):
-    """Depthwise causal conv over (B, T, C)."""
-    w = p["conv_w"].astype(xbc.dtype)                  # (C, W)
-    c = xbc.shape[-1]
+def _causal_conv(w, b, xs, cfg):
+    """Depthwise causal conv over (B, T, C); w: (C, W), b: (C,). The bias
+    and silu run in float32 (the bias's gradient sums over every position)."""
+    w = w.astype(xs.dtype)
+    c = xs.shape[-1]
     out = lax.conv_general_dilated(
-        xbc, w.T[:, None, :],                          # (W, 1, C)
+        xs, w.T[:, None, :],                           # (W, 1, C)
         window_strides=(1,), padding=[(cfg.conv_width - 1, 0)],
         dimension_numbers=("NWC", "WIO", "NWC"), feature_group_count=c)
-    return jax.nn.silu(out + p["conv_b"].astype(xbc.dtype))
+    return jax.nn.silu(out.astype(jnp.float32) + b).astype(xs.dtype)
+
+
+def _conv_tail(xs, cfg):
+    """Decode conv state: the last W-1 raw (pre-conv) inputs, left-padded
+    where t < W-1."""
+    t, w1 = xs.shape[1], cfg.conv_width - 1
+    tail = xs[:, -w1:, :] if t >= w1 else jnp.pad(
+        xs, ((0, 0), (w1 - t, 0), (0, 0)))
+    return tail.astype(jnp.dtype(cfg.dtype))
 
 
 def _ssd_scan(x, bmat, cmat, dt, a, cfg, init_state=None):
@@ -109,51 +131,62 @@ def _ssd_scan(x, bmat, cmat, dt, a, cfg, init_state=None):
     return y, state
 
 
-def mamba_forward(p: dict, u: jax.Array, cfg, state=None):
-    """u: (B, T, D) -> (out (B, T, D), decode-ready state dict)."""
+def mamba_forward(p: dict, u: jax.Array, cfg, state=None, scope="mamba2"):
+    """u: (B, T, D) -> (out (B, T, D), decode-ready state dict). The
+    projections, conv and gated norm run under ``jax.named_scope`` of
+    ``<scope>.mamba``, the chunked SSD under ``<scope>.ssd``."""
     b, t, _ = u.shape
-    d_in, nheads, conv_dim = dims(cfg)
-    z, x, bmat, cmat, dt = _split_proj(p, u, cfg)
-    xbc_raw = jnp.concatenate([x, bmat, cmat], axis=-1)
-    xbc = _causal_conv(p, xbc_raw, cfg)
-    x, bmat, cmat = jnp.split(xbc, [d_in, d_in + cfg.ssm_state], axis=-1)
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
-    a = -jnp.exp(p["a_log"])
-    xh = x.reshape(b, t, nheads, cfg.ssm_headdim)
-    y, fstate = _ssd_scan(xh, bmat, cmat, dt, a, cfg,
-                          None if state is None else state["ssm"])
-    y = y + xh * p["d_skip"].astype(x.dtype)[None, None, :, None]
-    y = y.reshape(b, t, d_in)
-    y = rms_norm(y * jax.nn.silu(z), p["gate_norm"], cfg.norm_eps)
-    # conv state = last W-1 raw (pre-conv) inputs, left-padded if t < W-1
-    w1 = cfg.conv_width - 1
-    tail = xbc_raw[:, -w1:, :] if t >= w1 else jnp.pad(
-        xbc_raw, ((0, 0), (w1 - t, 0), (0, 0)))
-    return dense(p["out_proj"], y), {"conv": tail.astype(jnp.dtype(cfg.dtype)),
-                                     "ssm": fstate}
+    d_in, nheads, _ = dims(cfg)
+    n = cfg.ssm_state
+    with jax.named_scope(f"{scope}.mamba"):
+        z, x_raw, bc_raw, dt = _project(p, u)
+        x = _causal_conv(p["conv_x_w"], p["conv_x_b"], x_raw, cfg)
+        bc = _causal_conv(p["conv_bc_w"], p["conv_bc_b"], bc_raw, cfg)
+        bmat, cmat = bc[..., :n], bc[..., n:]
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
+        a = -jnp.exp(p["a_log"])
+        xh = x.reshape(b, t, nheads, cfg.ssm_headdim)
+    with jax.named_scope(f"{scope}.ssd"):
+        y, fstate = _ssd_scan(xh, bmat, cmat, dt, a, cfg,
+                              None if state is None else state["ssm"])
+    with jax.named_scope(f"{scope}.mamba"):
+        y = (y.astype(jnp.float32) + xh.astype(jnp.float32)
+             * p["d_skip"][None, None, :, None]).astype(x.dtype)
+        y = y.reshape(b, t, d_in)
+        y = rms_norm(y * jax.nn.silu(z), p["gate_norm"], cfg.norm_eps)
+        out = dense(p["out_proj"], y)
+    return out, {"conv_x": _conv_tail(x_raw, cfg),
+                 "conv_bc": _conv_tail(bc_raw, cfg), "ssm": fstate}
 
 
 def init_mamba_state(cfg, batch: int):
-    d_in, nheads, conv_dim = dims(cfg)
+    d_in, nheads, _ = dims(cfg)
+    w1, dt = cfg.conv_width - 1, jnp.dtype(cfg.dtype)
     return {
-        "conv": jnp.zeros((batch, cfg.conv_width - 1, conv_dim), jnp.dtype(cfg.dtype)),
+        "conv_x": jnp.zeros((batch, w1, d_in), dt),
+        "conv_bc": jnp.zeros((batch, w1, 2 * cfg.ssm_state), dt),
         "ssm": jnp.zeros((batch, nheads, cfg.ssm_headdim, cfg.ssm_state), jnp.float32),
     }
+
+
+def _conv_step(w, b, window):
+    """One output of the depthwise conv over the (B, W, C) window."""
+    return jax.nn.silu(jnp.einsum("bwc,cw->bc", window, w.astype(window.dtype))
+                       + b.astype(window.dtype))[:, None, :]
 
 
 def mamba_decode(p: dict, u: jax.Array, state: dict, cfg):
     """Single-step recurrence. u: (B, 1, D). Returns (out (B,1,D), state)."""
     b = u.shape[0]
-    d_in, nheads, conv_dim = dims(cfg)
-    z, x, bmat, cmat, dt = _split_proj(p, u, cfg)
-    xbc = jnp.concatenate([x, bmat, cmat], axis=-1)    # (B,1,C)
-    # rolling conv state
-    window = jnp.concatenate([state["conv"], xbc], axis=1)      # (B,W,C)
-    w = p["conv_w"].astype(xbc.dtype)                  # (C, W)
-    conv_out = jnp.einsum("bwc,cw->bc", window, w) + p["conv_b"].astype(xbc.dtype)
-    xbc1 = jax.nn.silu(conv_out)[:, None, :]
-    new_conv = window[:, 1:, :]
-    x, bmat, cmat = jnp.split(xbc1, [d_in, d_in + cfg.ssm_state], axis=-1)
+    d_in, nheads, _ = dims(cfg)
+    n = cfg.ssm_state
+    z, x, bc, dt = _project(p, u)
+    # rolling conv states
+    win_x = jnp.concatenate([state["conv_x"], x], axis=1)       # (B,W,d_in)
+    win_bc = jnp.concatenate([state["conv_bc"], bc], axis=1)    # (B,W,2N)
+    x = _conv_step(p["conv_x_w"], p["conv_x_b"], win_x)
+    bc = _conv_step(p["conv_bc_w"], p["conv_bc_b"], win_bc)
+    bmat, cmat = bc[..., :n], bc[..., n:]
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])[:, 0]   # (B,H)
     a = -jnp.exp(p["a_log"])
     decay = jnp.exp(dt * a[None, :])                   # (B,H)
@@ -166,4 +199,6 @@ def mamba_decode(p: dict, u: jax.Array, state: dict, cfg):
     y = y + xh * p["d_skip"][None, :, None]
     y = y.reshape(b, 1, d_in).astype(u.dtype)
     y = rms_norm(y * jax.nn.silu(z), p["gate_norm"], cfg.norm_eps)
-    return dense(p["out_proj"], y), {"conv": new_conv, "ssm": new_ssm}
+    return dense(p["out_proj"], y), {"conv_x": win_x[:, 1:, :],
+                                     "conv_bc": win_bc[:, 1:, :],
+                                     "ssm": new_ssm}

@@ -13,7 +13,7 @@ from __future__ import annotations
 import functools
 from types import SimpleNamespace
 
-from repro.models import decoder, whisper, xlstm, zamba
+from repro.models import decoder, granite_hybrid, whisper, xlstm, zamba
 
 
 def get_model(cfg) -> SimpleNamespace:
@@ -37,6 +37,13 @@ def get_model(cfg) -> SimpleNamespace:
         def prefill(params, batch, *, window=0, num_groups=1):
             return zamba.prefill(params, batch["tokens"], cfg,
                                  window=window, num_groups=num_groups)
+    elif fam == "granite_hybrid":
+        mod = granite_hybrid
+
+        def prefill(params, batch, *, window=0, num_groups=1):
+            return granite_hybrid.prefill(params, batch["tokens"], cfg,
+                                          window=window,
+                                          num_groups=num_groups)
     elif fam == "audio":
         mod = whisper
 

@@ -12,6 +12,7 @@ honestly in cost_analysis and is a hillclimb target (see EXPERIMENTS.md §Perf).
 """
 from __future__ import annotations
 
+import contextlib
 import re
 from typing import Any
 
@@ -31,6 +32,18 @@ def set_rules(rules: dict[str, Any]) -> None:
 
 def clear_rules() -> None:
     set_rules({})
+
+
+@contextlib.contextmanager
+def activation_rules(rules: dict[str, Any]):
+    """``set_rules(rules)`` for the duration of the block (a trace), then
+    the rules that were in place before."""
+    prev = dict(_RULES)
+    set_rules(rules)
+    try:
+        yield
+    finally:
+        set_rules(prev)
 
 
 def hint(x, name: str):
@@ -98,10 +111,12 @@ _PARAM_RULES: list[tuple[re.Pattern, tuple[int, ...]]] = [
         # MoE (experts on model = expert parallelism)
         (r"moe/router/w$",                    ()),            # (D, E)
         (r"moe/we_[igo]$",                    (-3,)),         # (E, D, F)
-        # mamba2 / ssd
-        (r"mamba/in_proj/w$",                 (-1,)),         # (D, X)
-        (r"mamba/conv_w$",                    (-2,)),         # (C, W)
-        (r"mamba/(conv_b|a_log|dt_bias|d_skip|gate_norm)$", (-1,)),
+        # mamba2 / ssd: heads on model; B/C (shared by every head) whole
+        (r"mamba/in_(z|x|dt)/w$",             (-1,)),         # (D, d_in|H)
+        (r"mamba/in_bc/w$",                   ()),            # (D, 2N)
+        (r"mamba/conv_x_w$",                  (-2,)),         # (d_in, W)
+        (r"mamba/conv_bc_[wb]$",              ()),            # (2N, W), (2N,)
+        (r"mamba/(conv_x_b|a_log|dt_bias|d_skip|gate_norm)$", (-1,)),
         (r"mamba/out_proj/w$",                (-2,)),         # (d_in, D)
         # xlstm
         (r"(mlstm|slstm)/(up|qkv|gates|gates_x)/w$", (-1,)),
@@ -178,7 +193,8 @@ _CACHE_RULES: list[tuple[re.Pattern, tuple | None]] = [
         # not divisible (e.g. whisper's 1500-frame cross-KV).
         (r"(^|/)(enc_)?[kv]$",   (4, -4, (-3, -2, -1))),  # (B, S, Hkv, hd)
         (r"(^|/)enc_x$",         (3, -3, ())),         # (B, S, D)
-        (r"(^|/)conv$",          (3, -3, (-1,))),      # (B, W, C)
+        (r"(^|/)conv(_x)?$",     (3, -3, (-1,))),      # (B, W, C)
+        (r"(^|/)conv_bc$",       (3, -3, ())),         # (B, W, 2N): shared
         (r"(^|/)ssm$",           (4, -4, (-3,))),      # (B, H, P, N)
         (r"(^|/)mC$",            (4, -4, (-3, -1))),   # (B, H, dv, dk)
         (r"(^|/)(mn|sn|sc|sh)$", (3, -3, (-2, -1))),   # (B, H, d)

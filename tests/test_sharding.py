@@ -6,7 +6,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro import optim
-from repro.configs import ARCHS, get_config
+from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.core import TrainState
 from repro.models import get_model
 from repro.models.sharding import cache_spec_tree, param_spec_tree
@@ -106,3 +106,45 @@ def test_experts_sharded():
     specs = param_spec_tree(params, MODEL)
     assert specs["layers"]["moe"]["we_g"] == P(None, "model", None, None)
     assert specs["layers"]["moe"]["router"]["w"] == P(None, None, None)
+
+
+def test_hybrid_specs_shard_heads_and_keep_b_c_whole():
+    """Over a 4-wide axis every head-indexed Mamba2 leaf splits by head
+    (so a rank holds its own heads' z, x, dt, conv and D), B and C stay
+    whole, and attention, MLP and vocabulary shard as in the decoder."""
+    cfg = get_smoke_config("granite-4.0-h-micro")    # mamba, attention
+    params = jax.eval_shape(get_model(cfg).init, jax.random.PRNGKey(0))
+    specs = param_spec_tree(params, 4)
+    m = specs["runs"][0]["mamba"]
+    last, mid = P(None, None, "model"), P(None, "model", None)
+    assert (m["in_z"]["w"], m["in_x"]["w"], m["in_dt"]["w"]) == (last,) * 3
+    assert m["in_bc"]["w"] == P(None, None, None)
+    assert m["conv_x_w"] == mid and m["conv_x_b"] == P(None, "model")
+    assert m["conv_bc_w"] == P(None, None, None)
+    assert m["conv_bc_b"] == P(None, None)
+    for k in ("a_log", "dt_bias", "d_skip", "gate_norm"):
+        assert m[k] == P(None, "model"), k
+    assert m["out_proj"]["w"] == mid
+    a = specs["runs"][1]["attn"]
+    assert a["wq"]["w"] == P(None, None, "model", None)    # 4 heads
+    assert a["wk"]["w"] == P(None, None, None, "model")    # 2 kv heads: hd
+    assert a["wo"]["w"] == mid
+    for r in specs["runs"]:
+        assert r["mlp"]["wi"]["w"] == last and r["mlp"]["wo"]["w"] == mid
+    assert specs["embed"] == P("model", None)
+
+
+def test_hybrid_sharded_step_matches_one_device():
+    """``tests/_granite_hybrid_mesh.py``: one tier step of the smoke hybrid
+    through ``sharded_train_step`` on 4 forced host devices equals the
+    unsharded step within float32 round-off. A process of its own, since
+    the host device count is fixed when JAX starts."""
+    import os
+    import subprocess
+    import sys
+    script = os.path.join(os.path.dirname(__file__), "_granite_hybrid_mesh.py")
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    out = subprocess.run([sys.executable, script], env=env, capture_output=True,
+                         text=True, timeout=600)
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-4000:]
+    assert "sharded step matches" in out.stdout
