@@ -10,4 +10,5 @@ from repro.core.compression.structured import (SubmodelSpec, expand_masks,
                                                submodel_spec)  # noqa: F401
 from repro.core.compression.apply import (active_param_count, compress_params,
                                           compress_with_masks, compressible,
-                                          payload_bits)  # noqa: F401
+                                          payload_bits,
+                                          tier_thresholds)  # noqa: F401

@@ -9,7 +9,9 @@ expose as configuration.
 
 Two entry points:
   - ``compress_with_masks(params, density, e_bits, m_bits)``: traced per-tier
-    scalars, prune+quant only — used by the tier-scanned datacenter step.
+    scalars, prune+quant only — used by the tier-scanned datacenter step,
+    which searches every tier's pruning thresholds once a step with
+    ``tier_thresholds`` and hands each tier its own.
   - ``compress_params(params, plan)``: static CompressionPlan, adds k-means
     clustering and structured width slicing — used by the FL runtimes.
 
@@ -30,16 +32,38 @@ import jax.numpy as jnp
 
 from repro.core.compression.clustering import cluster_ste
 from repro.core.compression.plan import CompressionPlan
-from repro.core.compression.pruning import magnitude_mask
+from repro.core.compression.pruning import magnitude_mask, search_thresholds
 from repro.core.compression.quantization import fake_quant_ste
 from repro.core.compression.structured import (compressible, expand_masks,
                                                slice_tree, submodel_spec)
 
 __all__ = ["compressible", "compress_with_masks", "compress_params",
-           "payload_bits", "active_param_count"]
+           "tier_thresholds", "payload_bits", "active_param_count"]
 
 
-def compress_with_masks(params, density, e_bits, m_bits, out_dtype=None):
+def tier_thresholds(params, plans: list[CompressionPlan]):
+    """Every tier's pruning threshold of every compressible leaf, searched
+    once for all tiers (``search_thresholds``): a params-shaped tree with
+    a ``[len(plans)]`` array at each compressible leaf and None elsewhere.
+    Only the distinct densities below 1 are searched; a tier of density
+    >= 1 gets 0, which its all-ones mask ignores. The
+    ``tier_compression.thresholds`` scope names the search."""
+    densities = sorted({p.density for p in plans if p.density < 1.0})
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    pruned = [i for i, (path, w) in enumerate(flat) if compressible(path, w)]
+    with jax.named_scope("tier_compression.thresholds"):
+        found = (search_thresholds([flat[i][1] for i in pruned], densities)
+                 if densities else [None] * len(pruned))
+        out = [None] * len(flat)
+        for i, thr in zip(pruned, found):
+            out[i] = jnp.stack([thr[densities.index(p.density)]
+                                if p.density < 1.0 else jnp.float32(0.0)
+                                for p in plans])
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def compress_with_masks(params, density, e_bits, m_bits, out_dtype=None,
+                        thresholds=None):
     """Traced-scalar compression (prune -> fake-quant, both STE).
 
     Returns (compressed_params, masks) where masks has a full-size 0/1 leaf
@@ -51,17 +75,23 @@ def compress_with_masks(params, density, e_bits, m_bits, out_dtype=None):
     but halves the bytes of every cross-shard weight movement the
     partitioner inserts downstream (measured on qwen2.5-32b train_4k).
     The cast's VJP restores f32 cotangents, so aggregation is unaffected.
+
+    thresholds: this tier's slice of ``tier_thresholds`` (a scalar at each
+    compressible leaf, None elsewhere); without it each leaf's threshold
+    is searched here.
     """
-    def one(path, w):
+    def one(path, w, thr=None):
         if not compressible(path, w):
             return w, jnp.float32(1.0)
-        m = magnitude_mask(w, density)
+        m = magnitude_mask(w, density, thr)
         cw = fake_quant_ste(w * m, e_bits, m_bits) * m
         if out_dtype is not None:
             cw = cw.astype(out_dtype)
         return cw, m.astype(jnp.float32)
 
-    flat = jax.tree_util.tree_map_with_path(lambda p, w: one(p, w), params)
+    flat = (jax.tree_util.tree_map_with_path(one, params)
+            if thresholds is None else
+            jax.tree_util.tree_map_with_path(one, params, thresholds))
     cparams = jax.tree.map(lambda t: t[0], flat, is_leaf=lambda t: isinstance(t, tuple))
     masks = jax.tree.map(lambda t: t[1], flat, is_leaf=lambda t: isinstance(t, tuple))
     return cparams, masks

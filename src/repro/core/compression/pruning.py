@@ -15,6 +15,15 @@ construction. 16 iterations over 12 decades give ~4e-4 log resolution.
 The resulting mask is still an EXACT magnitude threshold (every kept
 |w| >= every dropped |w|); only the keep-fraction carries the (tiny)
 quantile resolution error.
+
+Where the search runs: ``magnitude_mask`` searches its own threshold
+(the FL runtimes' ``compress_params``). The tier-scanned step
+(``core/steps.py``) instead calls ``search_thresholds`` once a step,
+before its scan over tiers, for every pruning density of its plans at
+once: each bisection step reads a leaf once and counts for all
+densities, and dense tiers search nothing. Per density the arithmetic is
+``_threshold``'s, so the threshold and the exact-threshold property
+above are unchanged, bit for bit.
 """
 from __future__ import annotations
 
@@ -26,29 +35,54 @@ ITERS = 16
 EPS = 1e-12            # dynamic range of the log search (12 decades)
 
 
+def _bracket(aw):
+    """The log bracket of the search over ``aw`` = |w|:
+    kept-fraction(exp(lo)) ~ 1, kept-fraction(exp(hi)) ~ 0."""
+    amax = jnp.max(aw) + 1e-30
+    return jnp.log(amax * EPS), jnp.log(amax)
+
+
+def _narrow(aw, lo, hi, density):
+    """One bisection step: one counting pass over ``aw``."""
+    mid = 0.5 * (lo + hi)
+    kept = jnp.mean((aw >= jnp.exp(mid)).astype(jnp.float32))
+    # too many kept -> raise the threshold (move lo up), else lower hi
+    return jnp.where(kept > density, mid, lo), jnp.where(kept > density, hi, mid)
+
+
 def _threshold(aw: jax.Array, density) -> jax.Array:
     """|w| threshold such that ~`density` fraction of weights survive."""
-    amax = jnp.max(aw) + 1e-30
-    lo = jnp.log(amax * EPS)      # kept-fraction(exp(lo)) ~ 1
-    hi = jnp.log(amax)            # kept-fraction(exp(hi)) ~ 0
-
     def step(carry, _):
-        lo, hi = carry
-        mid = 0.5 * (lo + hi)
-        kept = jnp.mean((aw >= jnp.exp(mid)).astype(jnp.float32))
-        # too many kept -> raise the threshold (move lo up), else lower hi
-        lo = jnp.where(kept > density, mid, lo)
-        hi = jnp.where(kept > density, hi, mid)
-        return (lo, hi), None
+        return _narrow(aw, *carry, density), None
 
-    (lo, hi), _ = lax.scan(step, (lo, hi), None, length=ITERS)
+    (lo, hi), _ = lax.scan(step, _bracket(aw), None, length=ITERS)
     return jnp.exp(lo)            # the >=density side of the bracket
 
 
-def magnitude_mask(w: jax.Array, density) -> jax.Array:
+def search_thresholds(leaves, densities) -> list[jax.Array]:
+    """``_threshold`` of every leaf for each of ``densities`` (static
+    floats < 1), found by one search: each of its ITERS bisection steps
+    reads every leaf once and counts for all densities. Each density runs
+    ``_threshold``'s bracket and step, so each threshold is the same bit
+    for bit. Returns one ``[len(densities)]`` array per leaf."""
+    aws = [lax.stop_gradient(jnp.abs(w)) for w in leaves]
+    brackets = [[_bracket(aw)] * len(densities) for aw in aws]
+
+    def step(brackets, _):
+        return [[_narrow(aw, lo, hi, d) for (lo, hi), d in zip(leaf, densities)]
+                for aw, leaf in zip(aws, brackets)], None
+
+    brackets, _ = lax.scan(step, brackets, None, length=ITERS)
+    return [jnp.stack([jnp.exp(lo) for lo, _ in leaf]) for leaf in brackets]
+
+
+def magnitude_mask(w: jax.Array, density, thr=None) -> jax.Array:
     """0/1 keep-mask (same dtype as w, stop-gradient), traced density OK.
-    density >= 1.0 short-circuits to all-ones."""
+    density >= 1.0 short-circuits to all-ones. ``thr``: the leaf's
+    threshold for ``density`` from ``search_thresholds``; without it the
+    search runs here."""
     aw = lax.stop_gradient(jnp.abs(w))  # threshold path is never differentiated
-    thr = _threshold(aw, density)
+    if thr is None:
+        thr = _threshold(aw, density)
     mask = jnp.where(density >= 1.0, jnp.ones_like(w), (aw >= thr).astype(w.dtype))
     return lax.stop_gradient(mask)

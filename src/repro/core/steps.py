@@ -4,9 +4,14 @@ simulation scale.
 ``make_hetero_train_step`` builds ONE SPMD program for a full heterogeneous
 federated round:
 
+  search every tier's pruning thresholds of every leaf, once, before the
+  scan (all tiers compress the same global params; one read of a leaf per
+  bisection step serves every pruning density, and dense tiers search
+  nothing — ``compression.tier_thresholds``)
   scan over device tiers t (sequential => memory is 1 gradient + 2
   accumulators regardless of tier count):
-      1. compress the global params with tier t's plan  (paper Fig. 1, down)
+      1. compress the global params with tier t's plan and thresholds
+         (paper Fig. 1, down)
       2. compute local gradients of the COMPRESSED model on tier t's
          sub-batch (straight-through; data-parallel mean over the mesh's
          data/pod axes = averaging within the tier's client cohort)
@@ -33,7 +38,8 @@ from jax import lax
 
 from repro.core.aggregation import accumulate, finalize, zeros_like_acc
 from repro.core.compression import (CompressionPlan, compress_params,
-                                    compress_with_masks, plan_arrays)
+                                    compress_with_masks, plan_arrays,
+                                    tier_thresholds)
 
 
 class TrainState:
@@ -76,12 +82,12 @@ def make_hetero_train_step(model, optimizer, plans: list[CompressionPlan],
 
         def tier_fn(carry, xs):
             num, den, loss_acc = carry
-            plan_t, batch_t = xs
+            plan_t, thr_t, batch_t = xs
 
             def loss_of(p):
                 cp, masks = compress_with_masks(
                     p, plan_t["density"], plan_t["e_bits"], plan_t["m_bits"],
-                    out_dtype=cdt)
+                    out_dtype=cdt, thresholds=thr_t)
                 return model.loss_fn(cp, batch_t, num_groups=num_groups), masks
 
             (loss, masks), grads = jax.value_and_grad(
@@ -93,7 +99,8 @@ def make_hetero_train_step(model, optimizer, plans: list[CompressionPlan],
         num0, den0 = zeros_like_acc(params)
         num0, den0 = constrain(num0), constrain(den0)
         (num, den, loss_sum), _ = lax.scan(
-            tier_fn, (num0, den0, jnp.float32(0.0)), (arrs, batch))
+            tier_fn, (num0, den0, jnp.float32(0.0)),
+            (arrs, tier_thresholds(params, plans), batch))
         grads = finalize((num, den))
         new_params, new_opt = optimizer.update(grads, state["opt"], params,
                                                step=state["step"])

@@ -247,13 +247,15 @@ def test_which_hybrid_leaves_each_tier_compresses(tier):
 
 
 # the StableHLO (no debug info) of granite-3-2b's tier step at its cell's
-# shapes before the hybrid's multipliers, scale and NoPE switch came in;
-# it changes with the JAX version, not with this repository's defaults
+# shapes, with every tier's pruning thresholds searched once before the
+# tier scan (``compression.tier_thresholds``); it changes with the JAX
+# version and with the step's program, not with Granite's multipliers or
+# NoPE switch at their identity defaults
 GRANITE_3_2B_STEP_SHA256 = \
-    "5cc5481257a9e25ce4b5425ec68b71178dcaeb229dad8fd53c0ef7ca14893925"
+    "b5d4ef647aca235c089a3a3408129ce826fc1af3f3d79487df795707f48bc52a"
 
 
-def test_granite_3_2b_step_lowers_to_the_same_program():
+def _granite_3_2b_step_text(**fields) -> str:
     c = json.loads((ROOT / "chipbench/configs/granite-3-2b.json").read_text())
     cfg = ModelConfig(
         name=c["name"], family="dense", num_layers=c["num_hidden_layers"],
@@ -261,7 +263,7 @@ def test_granite_3_2b_step_lowers_to_the_same_program():
         num_kv_heads=c["num_key_value_heads"], d_ff=c["intermediate_size"],
         vocab_size=c["vocab_size"], tie_embeddings=c["tie_word_embeddings"],
         rope_theta=c["rope_theta"], norm_eps=c["rms_norm_eps"],
-        dtype=c["compute_dtype"])
+        dtype=c["compute_dtype"], **fields)
     model = get_model(cfg)
     o = c["optimizer"]
     opt = optim.adamw(o["lr"], b1=o["b1"], b2=o["b2"], eps=o["eps"],
@@ -270,5 +272,17 @@ def test_granite_3_2b_step_lowers_to_the_same_program():
                    donate_argnums=(0,))
     state = jax.eval_shape(lambda k: TrainState.create(model, opt, k), KEY)
     batch = {"tokens": jax.ShapeDtypeStruct((4, 2, 1025), jnp.int32)}
-    text = step.lower(state, batch).as_text(debug_info=False)
+    return step.lower(state, batch).as_text(debug_info=False)
+
+
+def test_granite_3_2b_step_lowers_to_the_same_program():
+    """The pinned program, and the same program with Granite's multipliers
+    and RoPE set explicitly to the identity (the attention scale given as
+    1/sqrt(64), granite-3-2b's head size): at identity they leave the
+    step unchanged."""
+    text = _granite_3_2b_step_text()
     assert hashlib.sha256(text.encode()).hexdigest() == GRANITE_3_2B_STEP_SHA256
+    assert _granite_3_2b_step_text(
+        embedding_multiplier=1.0, residual_multiplier=1.0,
+        attention_multiplier=64 ** -0.5, logits_scaling=1.0,
+        use_rope=True) == text
